@@ -42,6 +42,7 @@ from benchmarks import (
     bench_transport,
 )
 from benchmarks.common import emit
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = [
     ("fig11", bench_overhead),
@@ -80,6 +81,7 @@ def main(argv=None) -> None:
         f"tags: {','.join(tag for tag, _ in MODULES)}",
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
     selected = MODULES
     if args.only:
         want = {t.strip() for t in args.only.split(",") if t.strip()}

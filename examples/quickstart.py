@@ -11,12 +11,14 @@ import numpy as np
 
 from repro.configs.wsi import WSIConfig
 from repro.core import BoundingBox, Intent, RegionTemplate, StorageRegistry
+from repro.launch.compile_cache import enable_compile_cache
 from repro.pipeline import FeatureStage, SegmentationStage, make_slide
 from repro.runtime import SchedulerConfig, SysEnv
 from repro.storage import DistributedMemoryStorage
 
 
 def main() -> None:
+    enable_compile_cache()
     tile = 96
     rgb, _ = make_slide(2, 2, tile, seed=0)  # (3, 192, 192) synthetic WSI
     h, w = rgb.shape[1:]
@@ -41,11 +43,11 @@ def main() -> None:
     feats = []
     for part2 in dom2.tiles((tile, tile)):
         part3 = BoundingBox((0,) + part2.lo, (3,) + part2.hi)
-        seg = SegmentationStage(cfg, impl="xla")
+        seg = SegmentationStage(cfg, impl="auto")
         seg.add_region_template(rt, "RGB", part3, Intent.INPUT, read_storage="DMS3")
         seg.add_region_template(rt, "Mask", part2, Intent.OUTPUT, storage="DMS2")
         seg.add_region_template(rt, "Hema", part2, Intent.OUTPUT, storage="DMS2")
-        feat = FeatureStage(cfg, impl="xla")
+        feat = FeatureStage(cfg, impl="auto")
         feat.add_region_template(rt, "Mask", part2, Intent.INPUT, read_storage="DMS2")
         feat.add_region_template(rt, "Hema", part2, Intent.INPUT, read_storage="DMS2")
         feat.add_dependency(seg)

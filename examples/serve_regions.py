@@ -31,6 +31,7 @@ import time
 import numpy as np
 
 from repro.core import BoundingBox, ElementType, RegionKey
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.gateway import GatewayConfig, Overloaded, RegionGateway
 from repro.storage import DistributedMemoryStorage, Tier, TieredStore
 
@@ -110,6 +111,7 @@ def run_round(read_fn, mixes, slide) -> float:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--clients", type=int, default=16)
     ap.add_argument("--reads", type=int, default=20, help="ROI reads per client")

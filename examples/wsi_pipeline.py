@@ -21,12 +21,14 @@ import numpy as np
 
 from repro.configs.wsi import WSIConfig
 from repro.core import BoundingBox, Intent, RegionTemplate
+from repro.launch.compile_cache import enable_compile_cache
 from repro.pipeline import FeatureStage, SegmentationStage, make_slide, make_wsi_storage
 from repro.runtime import SchedulerConfig, SysEnv
 from repro.storage import DiskStorage
 
 
 def main() -> None:
+    enable_compile_cache()
     mode = sys.argv[1] if len(sys.argv) > 1 else "dms"
     transport = sys.argv[2] if len(sys.argv) > 2 else "inproc"
     tile = 96
@@ -71,11 +73,11 @@ def main() -> None:
     t0 = time.time()
     for part2 in dom2.tiles((tile, tile)):
         part3 = BoundingBox((0,) + part2.lo, (3,) + part2.hi)
-        seg = SegmentationStage(cfg, impl="xla")
+        seg = SegmentationStage(cfg, impl="auto")
         seg.add_region_template(rt, "RGB", part3, Intent.INPUT, read_storage="DMS3")
         seg.add_region_template(rt, "Mask", part2, Intent.OUTPUT, storage="DMS2")
         seg.add_region_template(rt, "Hema", part2, Intent.OUTPUT, storage="DMS2")
-        feat = FeatureStage(cfg, impl="xla")
+        feat = FeatureStage(cfg, impl="auto")
         feat.add_region_template(rt, "Mask", part2, Intent.INPUT, read_storage="DMS2")
         feat.add_region_template(rt, "Hema", part2, Intent.INPUT, read_storage="DMS2")
         feat.add_dependency(seg)

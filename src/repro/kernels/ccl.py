@@ -7,7 +7,7 @@ min-label propagation within mask runs.  The 1-D recurrence
     m_j = min(v_j, m_{j-1} if pass_j else +inf)
 
 composes closed-form ((v', p') = (min(v2, v1 if p2 else inf), p1 & p2)),
-giving log-depth associative scans per direction.  The fixed point labels
+giving log-depth scans per direction (``kernels/scan.py``).  The fixed point labels
 every component by its minimum flat index — identical canonical labels to
 union-find, verified in tests.
 """
@@ -18,24 +18,29 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.scan import roll_scan
 
 _BIG = jnp.iinfo(jnp.int32).max
 
 
 def _combine(a, b):
+    # the pass flag p is a 0/1 int32: the scan rolls it, and Mosaic may
+    # not lower a roll of bool vectors
     v1, p1 = a
     v2, p2 = b
-    v = jnp.minimum(v2, jnp.where(p2, v1, _BIG))
-    return v, jnp.logical_and(p1, p2)
+    v = jnp.minimum(v2, jnp.where(p2 != 0, v1, _BIG))
+    return v, p1 & p2
 
 
 def _scan_dir(labels, mask, axis, reverse):
-    v, _ = jax.lax.associative_scan(_combine, (labels, mask), axis=axis, reverse=reverse)
-    return jnp.where(mask, jnp.minimum(labels, v), labels)
+    v, _ = roll_scan(_combine, (labels, mask), axis, reverse, roll=pltpu.roll)
+    return jnp.where(mask != 0, jnp.minimum(labels, v), labels)
 
 
 def _kernel(labels_ref, mask_ref, out_ref, *, n_sweeps: int):
-    mask = mask_ref[...] != 0
+    mask = mask_ref[...]  # int32 0/1
     labels = labels_ref[...]
 
     def sweep(_, l):

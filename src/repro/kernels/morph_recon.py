@@ -8,7 +8,8 @@ that the 1-D reconstruction recurrence
 
 is a composition of clamp functions f(x) = min(c, max(d, x)) which compose
 in closed form, so each directional sweep is a *log-depth associative
-scan* along sublanes/lanes — fully regular, VPU-friendly.  One kernel call
+scan* along sublanes/lanes — fully regular, VPU-friendly (the roll-based
+form of ``kernels/scan.py``, which Mosaic lowers).  One kernel call
 performs ``n_sweeps`` 4-direction sweeps over its VMEM tile; the ops
 wrapper iterates kernel calls to the global fixed point (block-synchronous
 relaxation).  Connectivity: 4-neighbor, matching ref.py.
@@ -20,6 +21,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.scan import roll_scan
 
 
 def _combine(a, b):
@@ -29,7 +33,7 @@ def _combine(a, b):
 
 
 def _scan_dir(j, mask, axis, reverse):
-    c, d = jax.lax.associative_scan(_combine, (mask, j), axis=axis, reverse=reverse)
+    c, d = roll_scan(_combine, (mask, j), axis, reverse, roll=pltpu.roll)
     return jnp.minimum(c, d)
 
 

@@ -1,12 +1,17 @@
 """Jit'd public wrappers for every kernel, with implementation dispatch.
 
 ``impl`` selects:
-  * ``"pallas"``   — the Pallas kernel (compiled on TPU, interpret=True
-                     elsewhere so CPU runs execute the same kernel body);
+  * ``"pallas"``   — the Pallas kernel.  Compiled on a TPU; off the TPU it
+                     runs in Pallas *interpret mode* (the same kernel body,
+                     emulated), which says nothing about the device;
   * ``"xla"``      — the pure-jnp reference (used for dry-run lowering and
                      as the oracle);
   * ``"auto"``     — pallas on TPU, xla elsewhere (the production default:
                      CPU hosts shouldn't pay interpret-mode overhead).
+
+Neither ``"pallas"`` nor ``"auto"`` fails off the TPU, so a run that must
+exercise the device asserts ``jax.default_backend() == "tpu"`` itself
+(``chip_smoke.py`` does).
 """
 from __future__ import annotations
 
@@ -60,8 +65,9 @@ def morph_recon(
 
 @functools.partial(jax.jit, static_argnames=("impl",))
 def fill_holes(mask01: jax.Array, impl: str = "auto") -> jax.Array:
-    # holes-filling reconstruction is driven from the border; ref covers both
-    return ref.fill_holes_ref(mask01)
+    marker, inv = ref.fill_holes_seed(mask01)
+    # the reference's iteration bound, so impl="xla" equals fill_holes_ref
+    return 1.0 - morph_recon(marker, inv, impl=impl, max_iters=256)
 
 
 # -- connected components ----------------------------------------------------------

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.scan import roll_scan
+
 # --------------------------------------------------------------------------
 # Color deconvolution (stain unmixing)
 # --------------------------------------------------------------------------
@@ -46,8 +48,14 @@ def stain_inverse(stain_matrix: np.ndarray = RUIFROK_HED) -> np.ndarray:
 def color_deconv_ref(rgb: jax.Array, minv: jax.Array, eps: float = 1e-6) -> jax.Array:
     """(..., 3, H, W) float in [0,1] -> (..., 3, H, W) stain densities."""
     od = -jnp.log10(jnp.clip(rgb, eps, 1.0))
-    # channels-first planar: out[s] = sum_c minv[c, s] * od[c]
-    return jnp.einsum("...chw,cs->...shw", od, minv)
+    # channels-first planar: out[s] = sum_c minv[c, s] * od[c], as f32
+    # multiply-adds in the kernel's order: an einsum becomes a dot, which a
+    # TPU runs in one bf16 pass at default precision
+    c0, c1, c2 = od[..., 0, :, :], od[..., 1, :, :], od[..., 2, :, :]
+    return jnp.stack(
+        [minv[0, s] * c0 + minv[1, s] * c1 + minv[2, s] * c2 for s in range(3)],
+        axis=-3,
+    )
 
 
 # --------------------------------------------------------------------------
@@ -61,7 +69,9 @@ def _recon_scan_1d(marker: jax.Array, mask: jax.Array, axis: int, reverse: bool)
     c=I (mask), d=J (marker); such functions compose closed-form:
       f2.f1 = (c', d') with c' = min(c2, max(d2, c1)), d' = max(d1, d2)
     so the whole row is a log-depth associative scan — the TPU-idiomatic
-    replacement for the paper's GPU wavefront queues.
+    replacement for the paper's GPU wavefront queues.  The scan is the
+    roll-based doubling form (``kernels/scan.py``): XLA's TPU compiler
+    takes minutes over a 4096-wide sweep of ``lax.associative_scan``.
     """
 
     def combine(a, b):
@@ -69,8 +79,7 @@ def _recon_scan_1d(marker: jax.Array, mask: jax.Array, axis: int, reverse: bool)
         c2, d2 = b
         return jnp.minimum(c2, jnp.maximum(d2, c1)), jnp.maximum(d1, d2)
 
-    axis = axis % marker.ndim  # associative_scan(reverse=) needs axis >= 0
-    c, d = jax.lax.associative_scan(combine, (mask, marker), axis=axis, reverse=reverse)
+    c, d = roll_scan(combine, (mask, marker), axis, reverse)
     return jnp.minimum(c, d)
 
 
@@ -101,14 +110,20 @@ def morph_recon_ref(marker: jax.Array, mask: jax.Array, max_iters: int = 256) ->
     return j
 
 
-def fill_holes_ref(mask01: jax.Array) -> jax.Array:
-    """Binary fill-holes via border-seeded reconstruction of the complement."""
+def fill_holes_seed(mask01: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """(marker, mask) of the fill-holes reconstruction: the complement of
+    ``mask01``, seeded from its pixels on the image border."""
     inv = 1.0 - mask01
     h, w = mask01.shape[-2], mask01.shape[-1]
     border = jnp.zeros_like(mask01)
     border = border.at[..., 0, :].set(1.0).at[..., h - 1, :].set(1.0)
     border = border.at[..., :, 0].set(1.0).at[..., :, w - 1].set(1.0)
-    marker = jnp.minimum(border, inv)
+    return jnp.minimum(border, inv), inv
+
+
+def fill_holes_ref(mask01: jax.Array) -> jax.Array:
+    """Binary fill-holes via border-seeded reconstruction of the complement."""
+    marker, inv = fill_holes_seed(mask01)
     background = morph_recon_ref(marker, inv)
     return 1.0 - background
 
@@ -173,8 +188,7 @@ def _ccl_scan_1d(labels: jax.Array, mask: jax.Array, axis: int, reverse: bool) -
         v = jnp.minimum(v2, jnp.where(p2, v1, big))
         return v, jnp.logical_and(p1, p2)
 
-    axis = axis % labels.ndim
-    v, _ = jax.lax.associative_scan(combine, (labels, mask), axis=axis, reverse=reverse)
+    v, _ = roll_scan(combine, (labels, mask), axis, reverse)
     return jnp.where(mask, jnp.minimum(labels, v), labels)
 
 

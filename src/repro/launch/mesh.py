@@ -14,13 +14,8 @@ import jax
 
 
 def _make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with explicit Auto axis types where the installed
-    jax supports them (``axis_types=`` and ``jax.sharding.AxisType`` only
-    exist from jax 0.5; Auto is already the default on older versions)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    """``jax.make_mesh`` with explicit Auto axis types."""
+    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

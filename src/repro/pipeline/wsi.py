@@ -66,13 +66,26 @@ def extract_object_rois(
     r = cfg.nucleus_roi
     ids = np.unique(labels)
     ids = ids[ids >= 0][: cfg.max_objects_per_tile]
-    rois = np.zeros((len(ids), r, r), np.float32)
-    boxes = np.zeros((len(ids), 4), np.int32)
+    n = len(ids)
+    rois = np.zeros((n, r, r), np.float32)
+    boxes = np.zeros((n, 4), np.int32)
     h, w = labels.shape
-    for i, oid in enumerate(ids):
-        ys, xs = np.nonzero(labels == oid)
-        y0, y1 = ys.min(), ys.max() + 1
-        x0, x1 = xs.min(), xs.max() + 1
+    # every object's bounding box in one pass over the labelled pixels
+    # (ids holds every label up to ids[-1], so k < n means "selected")
+    flat = np.flatnonzero(labels >= 0)
+    k = np.searchsorted(ids, labels.ravel()[flat])
+    sel = k < n
+    k = k[sel]
+    ys, xs = np.divmod(flat[sel], w)
+    ymin, xmin = np.full(n, h), np.full(n, w)
+    ymax, xmax = np.full(n, -1), np.full(n, -1)
+    np.minimum.at(ymin, k, ys)
+    np.minimum.at(xmin, k, xs)
+    np.maximum.at(ymax, k, ys)
+    np.maximum.at(xmax, k, xs)
+    for i in range(n):
+        y0, y1 = ymin[i], ymax[i] + 1
+        x0, x1 = xmin[i], xmax[i] + 1
         cy, cx = (y0 + y1) // 2, (x0 + x1) // 2
         y0 = np.clip(cy - r // 2, 0, max(h - r, 0))
         x0 = np.clip(cx - r // 2, 0, max(w - r, 0))

@@ -5,14 +5,13 @@ import sys
 # dryrun-only, per the brief). Keep hypothesis deadlines off: CI boxes jit.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-# Make `from tests._prop import ...` work regardless of rootdir layout.
+# Make `from tests.<module> import ...` work regardless of rootdir layout.
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from tests._prop import HAVE_HYPOTHESIS, settings
+from hypothesis import settings  # noqa: E402
 
-if HAVE_HYPOTHESIS:
-    settings.register_profile("ci", deadline=None, max_examples=25, derandomize=True)
-    settings.load_profile("ci")
+settings.register_profile("ci", deadline=None, max_examples=25, derandomize=True)
+settings.load_profile("ci")
 
 # The envdrift marker machinery that used to live here is gone: the jax
 # API drifts it tracked (jax.sharding.AxisType, jax.shard_map) are fixed

@@ -1,7 +1,8 @@
 """BoundingBox unit + property tests."""
 import numpy as np
 import pytest
-from tests._prop import given, st
+from hypothesis import given
+from hypothesis import strategies as st
 
 from repro.core import BoundingBox, union_all
 

@@ -17,10 +17,7 @@ from jax.sharding import PartitionSpec as P
 import sys
 sys.path.insert(0, "src")
 from repro.train.compression import compressed_psum
-
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:  # pre-0.6 jax only ships the experimental spelling
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 mesh = jax.make_mesh((2, 4), ("pod", "data"))
 rng = np.random.default_rng(0)

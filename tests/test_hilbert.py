@@ -1,5 +1,6 @@
 """Hilbert / Morton SFC property tests (DHT routing foundation)."""
-from tests._prop import given, st
+from hypothesis import given
+from hypothesis import strategies as st
 
 from repro.core import (
     hilbert_d2xy,

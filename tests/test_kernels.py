@@ -3,9 +3,10 @@ swept over shapes/dtypes, plus hypothesis property tests on invariants."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from tests._prop import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
-from repro.kernels import ref
+from repro.kernels import ops, ref
 from repro.kernels.ccl import ccl_pallas
 from repro.kernels.color_deconv import color_deconv_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
@@ -66,6 +67,18 @@ def test_fill_holes_closes_a_donut():
     filled = np.asarray(ref.fill_holes_ref(jnp.asarray(m)))
     assert filled[15, 15] == 1.0
     assert filled[0, 0] == 0.0
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_ops_fill_holes_matches_ref(impl):
+    """ops.fill_holes honours impl: the border-seeded reconstruction runs
+    through ops.morph_recon (Pallas in interpret mode here)."""
+    m = (np.random.default_rng(5).random((40, 56)) < 0.6).astype(np.float32)
+    m[8:24, 8:24] = 1.0
+    m[14:18, 14:18] = 0.0  # a hole that must close
+    got = np.asarray(ops.fill_holes(jnp.asarray(m), impl=impl))
+    np.testing.assert_array_equal(got, ref.fill_holes_ref(jnp.asarray(m)))
+    assert got[15, 15] == 1.0
 
 
 # ---------------------------------------------------------------------------

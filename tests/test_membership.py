@@ -3,9 +3,10 @@ shares, wire roundtrip, adopt rule, and the TokenBucket pacer."""
 from fractions import Fraction
 
 import pytest
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.storage import RingView, TokenBucket, adopt_newer
-from tests._prop import HAVE_HYPOTHESIS, given, settings, st
 
 V = 64  # virtual-domain size used throughout (any value works)
 
@@ -114,36 +115,36 @@ def test_adopt_newer_keeps_highest_epoch():
 
 
 # ---------------------------------------------------------------------------
-# property tests (skip cleanly when hypothesis is absent)
+# property tests
 # ---------------------------------------------------------------------------
-if HAVE_HYPOTHESIS:
-    churn = st.lists(
-        st.tuples(st.sampled_from(["join", "leave"]), st.integers(0, 30)),
-        max_size=8,
-    )
+churn = st.lists(
+    st.tuples(st.sampled_from(["join", "leave"]), st.integers(0, 30)),
+    max_size=8,
+)
 
-    @given(n=st.integers(1, 12), ops=churn, vbits=st.integers(4, 10))
-    @settings(max_examples=40, deadline=None)
-    def test_prop_minimal_remap_and_exact_shares(n, ops, vbits):
-        vsize = 1 << vbits
-        ring = RingView.genesis(n)
-        for op, sid in ops:
-            if op == "join" and sid not in ring.servers:
-                new = ring.join(sid)
-                for rank in range(vsize):
-                    if new.owner(rank, vsize) != sid:
-                        assert new.owner(rank, vsize) == ring.owner(rank, vsize)
-            elif op == "leave" and sid in ring.servers and len(ring.servers) > 1:
-                new = ring.leave(sid)
-                for rank in range(vsize):
-                    if ring.owner(rank, vsize) != sid:
-                        assert new.owner(rank, vsize) == ring.owner(rank, vsize)
-            else:
-                continue
-            ring = new
-            m = len(ring.servers)
-            assert all(ring.share(s) == Fraction(1, m) for s in ring.servers)
-            assert RingView.from_json(ring.to_json()) == ring
+
+@given(n=st.integers(1, 12), ops=churn, vbits=st.integers(4, 10))
+@settings(max_examples=40, deadline=None)
+def test_prop_minimal_remap_and_exact_shares(n, ops, vbits):
+    vsize = 1 << vbits
+    ring = RingView.genesis(n)
+    for op, sid in ops:
+        if op == "join" and sid not in ring.servers:
+            new = ring.join(sid)
+            for rank in range(vsize):
+                if new.owner(rank, vsize) != sid:
+                    assert new.owner(rank, vsize) == ring.owner(rank, vsize)
+        elif op == "leave" and sid in ring.servers and len(ring.servers) > 1:
+            new = ring.leave(sid)
+            for rank in range(vsize):
+                if ring.owner(rank, vsize) != sid:
+                    assert new.owner(rank, vsize) == ring.owner(rank, vsize)
+        else:
+            continue
+        ring = new
+        m = len(ring.servers)
+        assert all(ring.share(s) == Fraction(1, m) for s in ring.servers)
+        assert RingView.from_json(ring.to_json()) == ring
 
 
 # ---------------------------------------------------------------------------

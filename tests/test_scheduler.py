@@ -2,7 +2,8 @@
 import threading
 
 import pytest
-from tests._prop import given, st
+from hypothesis import given
+from hypothesis import strategies as st
 
 from repro.runtime import (
     DeviceKind,

@@ -5,6 +5,7 @@ import pytest
 
 from repro.configs.wsi import WSIConfig
 from repro.core import BoundingBox, Intent, RegionTemplate, StorageRegistry
+from repro.kernels import ref
 from repro.pipeline import (
     FeatureStage,
     SegmentationStage,
@@ -59,6 +60,38 @@ def test_object_roi_extraction_fixed_size():
     assert rois.shape == (2, 16, 16)
     assert boxes.shape == (2, 4)
     assert (boxes[:, 2] <= 64).all() and (boxes[:, 3] <= 64).all()
+
+
+def _rois_per_object_loop(labels, intensity, cfg):
+    """One mask scan per object: the reference for extract_object_rois."""
+    r = cfg.nucleus_roi
+    ids = np.unique(labels)
+    ids = ids[ids >= 0][: cfg.max_objects_per_tile]
+    rois = np.zeros((len(ids), r, r), np.float32)
+    boxes = np.zeros((len(ids), 4), np.int32)
+    h, w = labels.shape
+    for i, oid in enumerate(ids):
+        ys, xs = np.nonzero(labels == oid)
+        cy, cx = (ys.min() + ys.max() + 1) // 2, (xs.min() + xs.max() + 1) // 2
+        y0 = np.clip(cy - r // 2, 0, max(h - r, 0))
+        x0 = np.clip(cx - r // 2, 0, max(w - r, 0))
+        crop = intensity[y0 : y0 + r, x0 : x0 + r]
+        rois[i, : crop.shape[0], : crop.shape[1]] = crop
+        boxes[i] = (y0, x0, min(y0 + r, h), min(x0 + r, w))
+    return rois, boxes
+
+
+@pytest.mark.parametrize("max_objects", [512, 7])
+def test_object_roi_extraction_matches_per_object_loop(max_objects):
+    rng = np.random.default_rng(1)
+    labels = ref.ccl_unionfind_host(rng.random((48, 80)) < 0.45)
+    intensity = rng.random((48, 80)).astype(np.float32)
+    cfg = WSIConfig(nucleus_roi=16, max_objects_per_tile=max_objects)
+    rois, boxes = extract_object_rois(labels, intensity, cfg)
+    want_rois, want_boxes = _rois_per_object_loop(labels, intensity, cfg)
+    assert len(boxes) == min(max_objects, len(np.unique(labels)) - 1)
+    np.testing.assert_array_equal(boxes, want_boxes)
+    np.testing.assert_array_equal(rois, want_rois)
 
 
 def test_rt_two_stage_pipeline_matches_plain(tile):
