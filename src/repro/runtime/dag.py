@@ -92,6 +92,8 @@ class Task:
         self.consumes = consumes  # data ids this task reads (DL)
         # RegionKey of the input data region (tier-locality transfer costs)
         self.region_key = region_key
+        # the stage instance that submitted it (set by the Worker; spans)
+        self.sid: int | None = None
         self.state = TaskState.PENDING
         self.result: Any = None
         self.error: BaseException | None = None

@@ -29,6 +29,7 @@ import time
 from typing import Any
 
 from repro.core.regions import STORAGE, DataRegion, RegionTemplate, StorageRegistry
+from repro.core.spans import span
 from repro.runtime.dag import (
     Stage,
     StageContext,
@@ -90,30 +91,46 @@ class Worker:
         try:
             if not self.alive:
                 return
-            stage.state = StageState.RUNNING
-            # Worker-local template copies (metadata only, paper S3.2).
-            # Copies are bound per-thread: a zombie execution on a dead
-            # worker must never leak its (mutated) templates into a retry.
-            local_templates = {
-                k: RegionTemplate.unpack(v.pack()) for k, v in stage.templates.items()
-            }
-            stage.bind_thread_templates(local_templates)
-            ctx = StageContext(
-                stage,
-                self,
-                submit_task=self.wrm.submit,
-                spawn_stage=self.manager.execute_component,
-            )
-            submitted: list[Task] = []
-            orig_submit = ctx._submit_task
+            # the span's self time (less its children on this thread) is
+            # the wait for the stage's tasks in _wait_tasks
+            with span(f"rt.stage/{stage.name}", sid=stage.sid, worker=self.wid,
+                      attempt=stage.attempts):
+                self._execute(stage)
+        except BaseException as e:  # noqa: BLE001
+            stage.error = e
+            if self.alive:
+                self.manager._notify_failed(stage, self.wid, e)
+        finally:
+            stage.unbind_thread_templates()
+            self._slots.release()
 
-            def tracking_submit(task: Task) -> None:
-                submitted.append(task)
-                orig_submit(task)
+    def _execute(self, stage: Stage) -> None:
+        stage.state = StageState.RUNNING
+        # Worker-local template copies (metadata only, paper S3.2).
+        # Copies are bound per-thread: a zombie execution on a dead
+        # worker must never leak its (mutated) templates into a retry.
+        local_templates = {
+            k: RegionTemplate.unpack(v.pack()) for k, v in stage.templates.items()
+        }
+        stage.bind_thread_templates(local_templates)
+        ctx = StageContext(
+            stage,
+            self,
+            submit_task=self.wrm.submit,
+            spawn_stage=self.manager.execute_component,
+        )
+        submitted: list[Task] = []
+        orig_submit = ctx._submit_task
 
-            ctx._submit_task = tracking_submit
+        def tracking_submit(task: Task) -> None:
+            task.sid = stage.sid
+            submitted.append(task)
+            orig_submit(task)
 
-            # (2) materialize inputs — overlaps other stages' compute
+        ctx._submit_task = tracking_submit
+
+        # (2) materialize inputs — overlaps other stages' compute
+        with span("rt.stage.inputs", sid=stage.sid):
             for b in stage.input_bindings():
                 rt = local_templates[b.template]
                 try:
@@ -142,11 +159,13 @@ class Worker:
                 local.instantiate(self.registry)
                 ctx.regions[(b.template, b.region)] = local
 
-            # (3) run the body; fine-grain tasks flow through the WRM
+        # (3) run the body; fine-grain tasks flow through the WRM
+        with span("rt.stage.body", sid=stage.sid):
             stage.result = stage.run(ctx)
-            self._wait_tasks(submitted)
+        self._wait_tasks(submitted)
 
-            # (4) stage outputs to global storage
+        # (4) stage outputs to global storage
+        with span("rt.stage.outputs", sid=stage.sid):
             for b in stage.output_bindings():
                 rt = local_templates[b.template]
                 region = rt.get(b.region)
@@ -159,18 +178,11 @@ class Worker:
                 out._location = "host"
                 out.output_storage = b.storage or region.output_storage
                 out.write(self.registry)
-            if not self.alive:
-                return  # died mid-stage: manager's heartbeat will requeue
-            # expose the winning execution's templates for inspection
-            stage.templates = local_templates
-            self.manager._notify_done(stage, self.wid)
-        except BaseException as e:  # noqa: BLE001
-            stage.error = e
-            if self.alive:
-                self.manager._notify_failed(stage, self.wid, e)
-        finally:
-            stage.unbind_thread_templates()
-            self._slots.release()
+        if not self.alive:
+            return  # died mid-stage: manager's heartbeat will requeue
+        # expose the winning execution's templates for inspection
+        stage.templates = local_templates
+        self.manager._notify_done(stage, self.wid)
 
     def _wait_tasks(self, tasks: list[Task]) -> None:
         from repro.runtime.dag import TaskState

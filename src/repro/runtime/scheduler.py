@@ -30,6 +30,7 @@ import heapq
 import threading
 from typing import Callable, Iterable
 
+from repro.core.spans import span
 from repro.runtime.dag import DeviceKind, Task, TaskState
 from repro.storage.tiers import TIER_BANDWIDTH
 
@@ -203,7 +204,6 @@ class ThreadedWRM:
         self._shutdown = False
         self._last_on: dict[int, Task | None] = {d.did: None for d in self.devices}
         self.completed: list[Task] = []
-        self.profile: dict[str, dict] = {}
         self._threads = [
             threading.Thread(target=self._loop, args=(d,), daemon=True, name=f"wrm-{d}")
             for d in self.devices
@@ -229,41 +229,22 @@ class ThreadedWRM:
                     if task is None:
                         self._cv.wait(timeout=0.05)
                 task.state = TaskState.RUNNING
-            import time as _time
-
-            t0 = _time.perf_counter()
             try:
-                fn = task.fn_for(dev.kind)
-                task.result = fn(*task.args, **task.kwargs) if fn else None
+                with span(f"rt.task/{task.name}", sid=task.sid, kind=dev.kind.name.lower()):
+                    fn = task.fn_for(dev.kind)
+                    task.result = fn(*task.args, **task.kwargs) if fn else None
                 task.state = TaskState.DONE
             except BaseException as e:  # noqa: BLE001 - surfaced via task.error
                 task.error = e
                 task.state = TaskState.FAILED
-            dt = _time.perf_counter() - t0
             task.ran_on = dev.kind
             with self._cv:
-                prof = self.profile.setdefault(
-                    task.name, {"cpu_s": 0.0, "accel_s": 0.0, "cpu_n": 0, "accel_n": 0}
-                )
-                if dev.kind == DeviceKind.CPU:
-                    prof["cpu_s"] += dt
-                    prof["cpu_n"] += 1
-                else:
-                    prof["accel_s"] += dt
-                    prof["accel_n"] += 1
                 self._last_on[dev.did] = task
                 self.completed.append(task)
                 if task.state == TaskState.DONE:
                     self.deps.release(task, self.ready)
                 self._outstanding -= 1
                 self._cv.notify_all()
-
-    def measured_speedup(self, name: str) -> float | None:
-        """Online EWMA-free estimate: mean cpu time / mean accel time."""
-        p = self.profile.get(name)
-        if not p or not p["cpu_n"] or not p["accel_n"]:
-            return None
-        return (p["cpu_s"] / p["cpu_n"]) / max(p["accel_s"] / p["accel_n"], 1e-12)
 
     def wait_all(self) -> None:
         with self._cv:

@@ -37,7 +37,6 @@ import os
 import queue
 import random
 import threading
-import time
 import uuid
 
 import numpy as np
@@ -64,7 +63,6 @@ class DiskStats:
     sessions: int = 0
     bytes_written: int = 0
     bytes_read: int = 0
-    wall_write_s: float = 0.0
     virtual_io_s: float = 0.0
     virtual_sync_s: float = 0.0
     virtual_comm_s: float = 0.0
@@ -292,7 +290,6 @@ class DiskStorage:
 
     def _write_session(self, group: _IOGroup | None, chunks: list[_Chunk]) -> None:
         """One (possibly grouped) write session producing a single file."""
-        t0 = time.perf_counter()
         fname = f"chunk-{uuid.uuid4().hex}.bin"
         path = os.path.join(self.root, fname)
         entries: list[_ManifestEntry] = []
@@ -324,7 +321,6 @@ class DiskStorage:
             self.stats.files_written += 1
             self.stats.sessions += 1
             self.stats.bytes_written += offset
-            self.stats.wall_write_s += time.perf_counter() - t0
             self.stats.virtual_io_s += (
                 self.cost.file_open_cost + offset / self.cost.disk_bandwidth
             )

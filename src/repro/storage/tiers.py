@@ -43,6 +43,7 @@ import numpy as np
 
 from repro.core.bbox import BoundingBox
 from repro.core.regions import RegionKey, StorageBackend
+from repro.core.spans import span
 from repro.storage.placement import Placement, PlacementPolicy
 
 # Per-tier staging bandwidth defaults (bytes/s) used by the runtime to
@@ -298,7 +299,8 @@ class TieredStore:
         placement = self.policy.place(key, bb, arr.nbytes, arr.dtype)
         ti = self._tier_index(placement.tier)
         tier = self.tiers[ti]
-        tier.backend.put(key, bb, arr)
+        with span(f"rt.tier.put/{tier.name}", bytes=arr.nbytes):
+            tier.backend.put(key, bb, arr)
         with self._lock:
             self._tombstones.discard(key)
             self._placement[key] = placement
@@ -312,7 +314,8 @@ class TieredStore:
         if ti != self._bottom:
             if wp == "write_through":
                 bottom = self.tiers[self._bottom]
-                bottom.backend.put(key, bb, arr)
+                with span(f"rt.tier.put/{bottom.name}", bytes=arr.nbytes):
+                    bottom.backend.put(key, bb, arr)
                 with self._lock:
                     self._admit(self._bottom, key, bb, arr.nbytes)
                     self._tier_gen[self._bottom][key] = gen
@@ -346,7 +349,7 @@ class TieredStore:
             had_resident = True
             ti = resident[0]
             try:
-                arr = self.tiers[ti].backend.get(key, roi)
+                arr = self._tier_get(ti, key, roi)
                 break
             except KeyError:
                 # either a concurrent demotion moved the payload (metadata
@@ -359,7 +362,7 @@ class TieredStore:
             # probe top-down and adopt the key so future reads are tracked
             for i, tier in enumerate(self.tiers):
                 try:
-                    arr = tier.backend.get(key, roi)
+                    arr = self._tier_get(i, key, roi)
                 except KeyError:
                     continue
                 ti = i
@@ -393,6 +396,14 @@ class TieredStore:
             self._promote(key, ti, roi, arr)
         return arr
 
+    def _tier_get(self, ti: int, key: RegionKey, roi: BoundingBox) -> np.ndarray:
+        """One tier backend's get, as an ``rt.tier.get/<tier>`` span."""
+        tier = self.tiers[ti]
+        with span(f"rt.tier.get/{tier.name}") as s:
+            arr = tier.backend.get(key, roi)
+            s.set_metadata(bytes=arr.nbytes)
+        return arr
+
     def _assemble_across_tiers(
         self, key: RegionKey, roi: BoundingBox
     ) -> tuple[np.ndarray | None, int | None]:
@@ -418,7 +429,7 @@ class TieredStore:
                     continue
                 part = bb.intersect(roi)
                 try:
-                    pieces.append((part, tier.backend.get(key, part)))
+                    pieces.append((part, self._tier_get(i, key, part)))
                 except KeyError:
                     continue  # this tier's coverage of part is partial
                 fastest = i if fastest is None else min(fastest, i)
@@ -470,8 +481,12 @@ class TieredStore:
                 self._hits[key] = 0
                 return
             self._moving.add(key)
+        # the box's bytes: the served ROI's bytes per element, times its volume
+        nbytes = served.nbytes // roi.volume * bb.volume
+        refetched = not (roi.contains(bb) and bb.contains(roi))
         try:
-            self._promote_locked(key, src, roi, served, bb, dst)
+            with span("rt.tier.promote", bytes=nbytes, refetched=refetched):
+                self._promote_locked(key, src, roi, served, bb, dst)
         finally:
             with self._lock:
                 self._moving.discard(key)
@@ -557,7 +572,10 @@ class TieredStore:
                 if victim is None:
                     # every candidate pinned or busy: over budget for now
                     return
-            if not self._demote(victim, ti):
+                nbytes = self._tier_bytes[ti].get(victim, 0)
+            with span("rt.tier.demote", bytes=nbytes):
+                demoted = self._demote(victim, ti)
+            if not demoted:
                 # mid-relocation or un-materializable: try the next victim
                 undemotable.add(victim)
 

@@ -148,18 +148,3 @@ def test_threaded_wrm_failure_surfaces():
     with pytest.raises(RuntimeError):
         wrm.wait_all()
     wrm.shutdown()
-
-
-def test_measured_speedup_profile():
-    import time
-
-    wrm = ThreadedWRM(make_devices(1, 1))
-    wrm.submit(Task("op", cpu_fn=lambda: time.sleep(0.02), accel_fn=lambda: time.sleep(0.002)))
-    wrm.submit(Task("op", cpu_fn=lambda: time.sleep(0.02), accel_fn=lambda: time.sleep(0.002)))
-    wrm.wait_all()
-    wrm.shutdown()
-    # with one CPU and one ACCEL thread both variants usually run; if both
-    # landed on the same device kind, the estimate is undefined -> skip
-    s = wrm.measured_speedup("op")
-    if s is not None:
-        assert s > 1.0
