@@ -109,13 +109,20 @@ class Task:
     def runnable_on(self, kind: DeviceKind) -> bool:
         return kind in self.variants or not self.variants
 
-    def fn_for(self, kind: DeviceKind) -> Callable[..., Any] | None:
+    def call(self, kind: DeviceKind) -> Any:
+        """Run the variant for ``kind`` (no variants: nothing to run)."""
         if not self.variants:
             return None
-        if kind in self.variants:
-            return self.variants[kind]
         # fall back to the other variant (a CPU can always emulate)
-        return next(iter(self.variants.values()))
+        fn = self.variants.get(kind) or next(iter(self.variants.values()))
+        return fn(*self.args, **self.kwargs)
+
+    def release(self) -> None:
+        """Drop the callables and arguments of a task that has run: a task
+        runs once, and its closures may hold a stage's device buffers,
+        which ``deps``/``children`` cycles would otherwise keep alive."""
+        self.variants.clear()
+        self.args, self.kwargs = (), {}
 
     def __repr__(self) -> str:
         return f"Task#{self.tid}({self.name} state={self.state.name} S={self.speedup:.1f})"

@@ -203,7 +203,7 @@ class ThreadedWRM:
         self._outstanding = 0
         self._shutdown = False
         self._last_on: dict[int, Task | None] = {d.did: None for d in self.devices}
-        self.completed: list[Task] = []
+        self._failed: list[Task] = []
         self._threads = [
             threading.Thread(target=self._loop, args=(d,), daemon=True, name=f"wrm-{d}")
             for d in self.devices
@@ -231,18 +231,19 @@ class ThreadedWRM:
                 task.state = TaskState.RUNNING
             try:
                 with span(f"rt.task/{task.name}", sid=task.sid, kind=dev.kind.name.lower()):
-                    fn = task.fn_for(dev.kind)
-                    task.result = fn(*task.args, **task.kwargs) if fn else None
+                    task.result = task.call(dev.kind)
                 task.state = TaskState.DONE
             except BaseException as e:  # noqa: BLE001 - surfaced via task.error
                 task.error = e
                 task.state = TaskState.FAILED
             task.ran_on = dev.kind
+            task.release()
             with self._cv:
                 self._last_on[dev.did] = task
-                self.completed.append(task)
                 if task.state == TaskState.DONE:
                     self.deps.release(task, self.ready)
+                else:
+                    self._failed.append(task)
                 self._outstanding -= 1
                 self._cv.notify_all()
 
@@ -250,9 +251,10 @@ class ThreadedWRM:
         with self._cv:
             while self._outstanding > 0:
                 self._cv.wait(timeout=0.05)
-        failed = [t for t in self.completed if t.state == TaskState.FAILED]
-        if failed:
-            raise RuntimeError(f"{len(failed)} task(s) failed") from failed[0].error
+        if self._failed:
+            raise RuntimeError(
+                f"{len(self._failed)} task(s) failed"
+            ) from self._failed[0].error
 
     def shutdown(self) -> None:
         with self._cv:

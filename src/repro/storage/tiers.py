@@ -13,7 +13,9 @@ Mechanics
 ---------
 * **Read-through + promotion** — a ``get`` is served from the fastest
   tier holding the key; repeated reads (``promote_after``) promote the
-  region one tier up (towards RAM).
+  region one tier up (towards RAM).  A region whose box exceeds the
+  destination tier's capacity is declined from its size alone, before
+  any read.
 * **Capacity-triggered demotion** — when a bounded tier fills up, LRU
   victims are *spilled* to the next tier down (optionally re-blocked at
   ROI granularity via the placement policy), never dropped.
@@ -88,6 +90,9 @@ class TierStats:
     misses: int = 0
     puts: int = 0
     promotions: int = 0
+    # promotions refused because the box exceeds this tier's capacity,
+    # decided from the box's size before any fetch
+    promotions_declined: int = 0
     demotions: int = 0
     flushes: int = 0
     flush_failures: int = 0  # drain() could not materialize the key
@@ -472,17 +477,27 @@ class TieredStore:
         dst = 0
         with self._lock:
             bb = self._bb.get(key)
+            if bb is None:
+                self._hits[key] = 0
+                return
+            # the box's bytes: the served ROI's bytes per element, times its
+            # volume.  A box over the destination's budget would be evicted
+            # right back out, so decline before fetching it.
+            nbytes = served.nbytes // roi.volume * bb.volume
+            cap = self.tiers[dst].capacity_bytes
+            if cap is not None and nbytes > cap:
+                self._hits[key] = 0
+                self.tiers[dst].stats.promotions_declined += 1
+                return
             # a stale top-tier leftover must not block re-promotion of a
             # fresher copy: compare generations, not mere residency
             dst_current = dst in self._resident.get(key, set()) and self._tier_gen[
                 dst
             ].get(key, 0) >= self._tier_gen[src].get(key, 0)
-            if bb is None or dst_current or key in self._moving:
+            if dst_current or key in self._moving:
                 self._hits[key] = 0
                 return
             self._moving.add(key)
-        # the box's bytes: the served ROI's bytes per element, times its volume
-        nbytes = served.nbytes // roi.volume * bb.volume
         refetched = not (roi.contains(bb) and bb.contains(roi))
         try:
             with span("rt.tier.promote", bytes=nbytes, refetched=refetched):
@@ -507,11 +522,6 @@ class TieredStore:
                 arr = self.tiers[src].backend.get(key, bb)
             except KeyError:
                 return  # partial coverage: promotion needs the full box
-        cap = self.tiers[dst].capacity_bytes
-        if cap is not None and arr.nbytes > cap:
-            with self._lock:
-                self._hits[key] = 0  # would be evicted right back out
-            return
         dst_backend = self.tiers[dst].backend
         with self._lock:
             src_gen = self._tier_gen[src].get(key, 0)

@@ -138,6 +138,37 @@ def test_threaded_wrm_executes_with_deps_and_variants():
     assert a.ran_on is not None
 
 
+def test_threaded_wrm_frees_what_finished_tasks_hold():
+    """A stage's intermediates die with its last task, not at the next
+    garbage collection: tasks linked by deps/children form cycles, so the
+    WRM drops a task's callables once it has run."""
+    import gc
+    import weakref
+
+    class Buffer:  # stands in for a device array a stage's closures hold
+        pass
+
+    def stage():
+        results = {"buf": Buffer()}
+        a = Task("a", cpu_fn=lambda: results.update(a=1))
+        b = Task("b", cpu_fn=lambda: results.update(b=1), deps=[a])
+        return a, b, weakref.ref(results["buf"])
+
+    wrm = ThreadedWRM(make_devices(2, 1), SchedulerConfig(policy="PATS"))
+    a, b, alive = stage()
+    gc.disable()
+    try:
+        wrm.submit(a)
+        wrm.submit(b)
+        wrm.wait_all()
+        assert a.ran_on is not None and b.ran_on is not None
+        del a, b
+        assert alive() is None
+    finally:
+        gc.enable()
+        wrm.shutdown()
+
+
 def test_threaded_wrm_failure_surfaces():
     wrm = ThreadedWRM(make_devices(1, 0))
 

@@ -1,6 +1,7 @@
 """Program spans (``repro.core.spans``): a 256x256 segmentation and feature
 run on the Manager/WRM runtime over tiered storage, under the profiler,
-puts each span where the runtime and the store cross a layer boundary."""
+puts each span where the runtime and the store cross a layer boundary; a
+bare tiered store shows the promotion's span."""
 import subprocess
 import sys
 from pathlib import Path
@@ -11,9 +12,10 @@ import pytest
 
 from bench import spans as bench_spans
 from repro.configs.wsi import WSIConfig
-from repro.core import BoundingBox, Intent, RegionTemplate
+from repro.core import BoundingBox, ElementType, Intent, RegionKey, RegionTemplate
 from repro.pipeline import FeatureStage, SegmentationStage, make_tile, make_wsi_storage
 from repro.runtime import SysEnv
+from repro.storage import MemoryTier, Tier, TieredStore
 
 EDGE = 256
 RGB_BYTES = 3 * EDGE * EDGE * 4
@@ -26,7 +28,7 @@ def traced(tmp_path_factory):
     root = tmp_path_factory.mktemp("spans")
     rgb, _ = make_tile(EDGE, num_nuclei=10, seed=5)
     # the RAM tier holds the mask and hematoxylin but not the RGB: its put
-    # is demoted at once, and its first get from DMS tries a promotion
+    # is demoted at once, and its get from DMS declines a promotion on size
     reg = make_wsi_storage(EDGE, EDGE, mode="tiered", tile=EDGE, root=str(root / "tiers"),
                            mem_capacity_bytes=RGB_BYTES * 3 // 4, promote_after=1)
     dom3 = BoundingBox((0, 0, 0), (3, EDGE, EDGE))
@@ -58,14 +60,20 @@ def traced(tmp_path_factory):
         for name in reg.names():
             reg.get(name).close()
     xplane = sorted(trace_dir.rglob("*.xplane.pb"))[-1]
+    assert feat.templates["Patient"].get("Features").data["features"].shape[1] == 9
+    rgb_mem = reg.get("DMS3").tier_stats()["MEM"]
+    return bench_spans.load(xplane)["spans"], _rt_stats(xplane), seg, feat, rgb_mem
+
+
+def _rt_stats(xplane) -> dict[str, list[dict]]:
+    """Each ``rt.`` span's stats, by name, from a profiler trace."""
     stats: dict[str, list[dict]] = {}
     for plane in jax.profiler.ProfileData.from_file(str(xplane)).planes:
         for line in plane.lines:
             for e in line.events:
                 if e.name.startswith("rt."):
                     stats.setdefault(e.name, []).append(dict(e.stats))
-    assert feat.templates["Patient"].get("Features").data["features"].shape[1] == 9
-    return bench_spans.load(xplane)["spans"], stats, seg, feat
+    return stats
 
 
 def _enclosing(host, span, prefix):
@@ -76,13 +84,13 @@ def _enclosing(host, span, prefix):
 
 
 def test_each_span_appears_nested_per_thread(traced):
-    host, _, _, _ = traced
+    host, _, _, _, _ = traced
     names = {h[0] for h in host}
     expected = {
         "rt.stage/Segmentation", "rt.stage/FeatureComputation", "rt.stage.inputs",
         "rt.stage.body", "rt.stage.outputs", "rt.task/Color deconv.", "rt.task/ObjectROIs",
         "rt.task/Features", "rt.tier.get/DMS", "rt.tier.get/MEM", "rt.tier.put/MEM",
-        "rt.tier.put/DMS", "rt.tier.promote", "rt.tier.demote",
+        "rt.tier.put/DMS", "rt.tier.demote",
     }
     assert expected <= names
     assert all(n.startswith("rt.") for n in names)
@@ -113,7 +121,7 @@ def test_each_span_appears_nested_per_thread(traced):
 
 
 def test_spans_carry_stage_ids_and_bytes(traced):
-    _, stats, seg, feat = traced
+    _, stats, seg, feat, rgb_mem = traced
     assert [s["sid"] for s in stats["rt.stage/Segmentation"]] == [seg.sid]
     assert [s["sid"] for s in stats["rt.stage/FeatureComputation"]] == [feat.sid]
     for s in stats["rt.stage/Segmentation"] + stats["rt.stage/FeatureComputation"]:
@@ -123,12 +131,34 @@ def test_spans_carry_stage_ids_and_bytes(traced):
     assert {s["kind"] for n, rows in stats.items() if n.startswith("rt.task/")
             for s in rows} <= {"cpu", "accel"}
     assert stats["rt.task/Color deconv."][0]["sid"] == seg.sid
-    # the RGB tile: staged, demoted from RAM, read from DMS, and a promotion
-    # of the whole box that needed no second read
+    # the RGB tile: staged, demoted from RAM, read from DMS, and its
+    # promotion declined on size, before any fetch: no promotion span
     assert RGB_BYTES in [s["bytes"] for s in stats["rt.tier.put/MEM"]]
     assert RGB_BYTES in [s["bytes"] for s in stats["rt.tier.demote"]]
     assert RGB_BYTES in [s["bytes"] for s in stats["rt.tier.get/DMS"]]
-    assert stats["rt.tier.promote"] == [{"bytes": RGB_BYTES, "refetched": False}]
+    assert "rt.tier.promote" not in stats
+    assert rgb_mem.promotions == 0 and rgb_mem.promotions_declined == 1
+
+
+@pytest.mark.parametrize("roi", [BoundingBox((0, 0), (64, 128)), BoundingBox((0, 0), (128, 128))],
+                         ids=["partial_roi", "whole_box"])
+def test_promote_span_carries_box_bytes(tmp_path, roi):
+    """A promotion that fits the RAM tier opens ``rt.tier.promote`` with
+    the whole box's bytes, and says whether the box was fetched again."""
+    box = BoundingBox((0, 0), (128, 128))
+    ts = TieredStore([Tier("MEM", MemoryTier(name="MEM"), box.volume * 4),
+                      Tier("DMS", MemoryTier(name="DMS"))], promote_after=1)
+    key = RegionKey("t", "hot", ElementType.FLOAT32)
+    ts.tiers[-1].backend.put(key, box, np.ones(box.shape, np.float32))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        ts.get(key, roi)
+    finally:
+        jax.profiler.stop_trace()
+        ts.close()
+    stats = _rt_stats(sorted(tmp_path.rglob("*.xplane.pb"))[-1])
+    assert stats["rt.tier.promote"] == [{"bytes": box.volume * 4, "refetched": roi != box}]
+    assert ts.locality(key) == "MEM" and ts.tier_stats()["MEM"].promotions == 1
 
 
 @pytest.mark.parametrize("module", ["repro.core.spans", "repro.storage.net",

@@ -76,6 +76,42 @@ def test_promotion_on_repeat_read():
     ts.close()
 
 
+class _CountingTier(MemoryTier):
+    """A memory tier that counts the gets made of it."""
+
+    def __init__(self, name: str) -> None:
+        super().__init__(name=name)
+        self.gets = 0
+
+    def get(self, key: RegionKey, roi: BoundingBox) -> np.ndarray:
+        self.gets += 1
+        return super().get(key, roi)
+
+
+@pytest.mark.parametrize("roi", [DOM, BoundingBox((0, 0), (128, 384))],
+                         ids=["partial_roi", "whole_box"])
+def test_oversize_promotion_declined_before_fetch(roi):
+    """A box larger than the RAM tier is never promoted, and the store
+    decides so from its size, without fetching the box."""
+    dms = _CountingTier("DMS")
+    ts = TieredStore(
+        [Tier("MEM", MemoryTier(name="MEM"), 2 * TILE_BYTES), Tier("DMS", dms)],
+        promote_after=2,
+    )
+    box = BoundingBox((0, 0), (128, 384))  # three tiles: over the RAM tier
+    k = _key("slide")
+    a = np.random.default_rng(3).random((128, 384)).astype(np.float32)
+    dms.put(k, box, a)
+    for _ in range(2):  # the second get crosses promote_after
+        np.testing.assert_array_equal(ts.get(k, roi), a[roi.local_slices(box)])
+    assert dms.gets == 2  # one per get: no fetch of the whole box
+    stats = ts.tier_stats()["MEM"]
+    assert stats.promotions == 0 and stats.promotions_declined == 1
+    assert stats.bytes_promoted == 0
+    assert ts.locality(k) == "DMS"
+    ts.close()
+
+
 def test_capacity_eviction_demotes_not_drops():
     ts = _mem_stack(capacity_tiles=2, write_policy="write_back")
     keys = [_key(f"r{i}") for i in range(4)]
