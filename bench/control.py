@@ -46,29 +46,21 @@ def bf16_answer(cls: dict, window: np.ndarray, threshold: float):
     return np.array([reference.chain_count(window, threshold, reference.BF16)], np.int32)
 
 
-def serve_control(config: dict, mix: dict, seed: int, slide: np.ndarray | None = None) -> list:
-    """The sampled answers of one run's schedule, computed in bfloat16."""
+def serve_control(config: dict, mix: dict, seed: int) -> list:
+    """The answers a run of the seed would compare (the same slide, the same
+    window's schedule and the same sample), computed in bfloat16."""
     from bench.systems import serve
 
     dep = serve.Serve.__new__(serve.Serve)
     dep.config, dep.mix = config, mix
-    if slide is None:
-        ty, tx = config["slide_tiles"]
-        t = config["tile"]
-        pool = synth.TilePool(seed, t, config["pool_base_tiles"], config["nuclei_per_512"],
-                              tuple(config["nucleus_radius"]))
-        slide = np.concatenate([np.concatenate([pool.tile((y * tx + x) % len(pool))
-                                                for x in range(tx)], axis=2)
-                                for y in range(ty)], axis=1)
-    dep.slide = slide
-    reqs = traffic.schedule(mix, slide.shape[1:], 10.0, seed)
+    dep.slide = slide = serve.make_slide(config, seed)
+    reqs = traffic.schedule(mix, slide.shape[1:], harness.benchmark()["run_seconds"], seed)
+    picked = serve.sample(mix, reqs, seed)
     dep.requests, dep.kept = [], {}
-    per_class: dict[int, int] = {}
     for r in reqs:
-        cls = mix["classes"][r.cls]
-        if per_class.get(r.cls, 0) >= cls["check"]:
+        if r.index not in picked:
             continue
-        per_class[r.cls] = per_class.get(r.cls, 0) + 1
+        cls = mix["classes"][r.cls]
         y, x = r.origin
         e = cls["edge"]
         fut = concurrent.futures.Future()

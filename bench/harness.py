@@ -161,6 +161,20 @@ def traced(seconds_fn, trace_dir: Path):
         jax.profiler.stop_trace()
 
 
+def breakdown(run: Run) -> dict:
+    """The traced window's costliest device ops and longest idle gaps.  The
+    gaps go by the program's spans (``bench/spans.py``) where the program
+    opened any, else by the benchmark's own (``bench/trace.py``)."""
+    from bench import spans
+    from bench import trace as tr
+
+    program = spans.read(run)
+    reduced = run.trace
+    if program and any(n.startswith(spans.PROGRAM) for n in program["spans"]):
+        reduced = dict(reduced, gaps=program["gaps"])
+    return tr.breakdown(reduced)
+
+
 def run_cell(name: str, seed: int, seconds: int, trace: bool, t_start: float) -> dict:
     """Returns the result line's object; raises NoChip before any work."""
     bench = benchmark()
@@ -227,13 +241,13 @@ def run_loaded(bench: dict, name: str, config: dict, mix: dict, seed: int, secon
     correct = all(value <= limit for _, value, limit in checks) and bool(checks)
 
     metrics = {}
+    run = Run(name, config, mix, counters, in_window, reduced, peaks)
     if not trace:
         e2e["setup_s"] = setup_s
         for m in bench["end_to_end"]:
             if name in m.get("workloads", [name]) and m["name"] in e2e:
                 metrics[m["name"]] = {"value": e2e[m["name"]], "unit": m["unit"]}
     else:
-        run = Run(name, config, mix, counters, in_window, reduced, peaks)
         for m in bench["per_layer"]:
             if name not in m.get("workloads", [name]):
                 continue
@@ -248,8 +262,6 @@ def run_loaded(bench: dict, name: str, config: dict, mix: dict, seed: int, secon
         "device": device,
     }
     if reduced is not None:
-        from bench import trace as tr
-
-        result["breakdown"] = tr.breakdown(reduced)
+        result["breakdown"] = breakdown(run)
     result["checks"] = {n: {"value": v, "limit": lim} for n, v, lim in checks}
     return result

@@ -4,7 +4,8 @@ up.  Builds the cell's deployment once and runs one window per rate.
     python3 bench/sweep.py --workload <config>.<traffic> --seed <n> --seconds <s> --rates 10,20,40
 
 Prints one JSON line per rate: p50 and p95 latency and answered requests per
-second of each operation.  The knee is the highest rate whose answered rate
+second of each operation.  The requests of the k-th rate are drawn from
+``seed + k``.  The knee is the highest rate whose answered rate
 keeps up with the offered one and whose p95 has not started to climb; the
 cell's mix file takes about four fifths of it.
 """
@@ -43,8 +44,10 @@ def main() -> int:
     dep = system.build(config, mix, args.seed, harness.Spans())
     print(f"sweep: set-up {time.perf_counter() - T_START:.1f} s", flush=True)
     try:
-        for rate in (float(r) for r in args.rates.split(",")):
-            out = dep.run(args.seconds, rate=rate)
+        # each rate's requests from a seed of their own: with one seed the
+        # rates' schedules share origins, which the derived cache then hits
+        for k, rate in enumerate(float(r) for r in args.rates.split(",")):
+            out = dep.run(args.seconds, rate=rate, seed=args.seed + k)
             print(json.dumps(dict(out, rate=rate, lateness_ms=dep.lateness)), flush=True)
     finally:
         dep.close()

@@ -25,6 +25,31 @@ def build(config: dict, mix: dict, seed: int, spans):
     return Serve(config, mix, seed, spans)
 
 
+def make_slide(config: dict, seed: int) -> np.ndarray:
+    """The slide to stage: the pool's tiles in an order drawn from the seed."""
+    ty, tx = config["slide_tiles"]
+    t = config["tile"]
+    pool = synth.TilePool(seed, t, config["pool_base_tiles"], config["nuclei_per_512"],
+                          tuple(config["nucleus_radius"]))
+    order = np.random.default_rng([seed, 1]).permutation(len(pool))
+    slide = np.empty((3, ty * t, tx * t), np.float32)
+    for j in range(ty * tx):
+        y, x = divmod(j, tx)
+        slide[:, y * t : (y + 1) * t, x * t : (x + 1) * t] = pool.tile(int(order[j % len(order)]))
+    return slide
+
+
+def sample(mix: dict, requests: list[traffic.Request], seed: int) -> set[int]:
+    """The indices of the requests the check compares: ``check`` of each
+    class, drawn from the seed."""
+    rng = np.random.default_rng([seed, 2])
+    picked: set[int] = set()
+    for k, cls in enumerate(mix["classes"]):
+        idx = [r.index for r in requests if r.cls == k]
+        picked |= {int(i) for i in rng.choice(idx, min(cls["check"], len(idx)), replace=False)}
+    return picked
+
+
 def _p95(values: np.ndarray) -> float:
     return float(np.percentile(values, 95)) if values.size else float("nan")
 
@@ -42,15 +67,7 @@ class Serve:
         t = config["tile"]
         self.extent = (ty * t, tx * t)
         with spans.span("bench.setup.tiles"):
-            pool = synth.TilePool(seed, t, config["pool_base_tiles"], config["nuclei_per_512"],
-                                  tuple(config["nucleus_radius"]))
-            order = np.random.default_rng([seed, 1]).permutation(len(pool))
-            self.slide = np.empty((3,) + self.extent, np.float32)
-            for j in range(ty * tx):
-                y, x = divmod(j, tx)
-                self.slide[:, y * t : (y + 1) * t, x * t : (x + 1) * t] = pool.tile(
-                    int(order[j % len(order)]))
-            del pool
+            self.slide = make_slide(config, seed)
         st = config["storage"]
         OUT.mkdir(parents=True, exist_ok=True)
         self._root = tempfile.mkdtemp(prefix="tiers_", dir=OUT)
@@ -100,15 +117,13 @@ class Serve:
             self.kept[req.index] = fut
         return fut
 
-    def run(self, seconds: float, rate: float | None = None) -> dict:
+    def run(self, seconds: float, rate: float | None = None, seed: int | None = None) -> dict:
+        """One window of the mix; ``rate`` and ``seed`` (of the requests, not
+        the slide) override the mix's and the build's, for a sweep."""
         mix = dict(self.mix, rate=rate or self.mix["rate"])
-        reqs = traffic.schedule(mix, self.extent, seconds, self.seed)
-        rng = np.random.default_rng([self.seed, 2])
-        self._sampled = set()
-        for k, cls in enumerate(mix["classes"]):
-            idx = [r.index for r in reqs if r.cls == k]
-            take = min(cls["check"], len(idx))
-            self._sampled |= set(int(i) for i in rng.choice(idx, take, replace=False))
+        seed = self.seed if seed is None else seed
+        reqs = traffic.schedule(mix, self.extent, seconds, seed)
+        self._sampled = sample(mix, reqs, seed)
         self.kept = {}
         loop = traffic.OpenLoop(reqs, self._submit)
         loop.run(drain_s=DRAIN_S)

@@ -1,7 +1,8 @@
 """Idle gaps put down to the program's spans (``bench/spans.py``), on a
 hand-built trace whose numbers are known, on a trace the profiler writes
 here, and on a ``wsi_4k.rt`` traced run recorded on a TPU v5e (the events
-``bench/spans.py`` keeps)."""
+``bench/spans.py`` keeps); and the result line's ``breakdown`` built from
+them."""
 from __future__ import annotations
 
 from pathlib import Path
@@ -123,3 +124,21 @@ def test_recorded_tpu_trace_puts_idle_time_under_program_spans():
     assert sum(program) >= 0.8 * (sum(runtime.values()) + sum(staging.values()))
     assert r["spans"]["rt.task/ObjectROIs"]["calls"] == 16
     assert r["groups"]["rt.tier"] > 0
+
+
+def test_result_breakdown_puts_idle_gaps_under_program_spans(monkeypatch):
+    events = trace.read_saved(DATA / "wsi_4k.rt.spans.json.gz")
+    program = spans.reduce(events)
+    run = Run("wsi_4k.rt", {}, {}, {}, {}, trace.reduce(events), {})
+    monkeypatch.setattr(spans, "read", lambda run: program)
+    b = harness.breakdown(run)
+    top = sorted(program["gaps"].items(), key=lambda kv: -kv[1])[:10]
+    assert b["idle_gaps"] == [[k, v] for k, v in top] and len(top) == 10
+    assert {"rt.tier.get/DMS", "rt.tier.put/DMS", "rt.tier.promote"} <= {k for k, _ in top}
+    assert b["device_ops"] == trace.breakdown(run.trace)["device_ops"]
+    # a program that opened no span of its own: the benchmark's spans alone
+    bare = dict(events, spans=[[*h, 0] for h in events["host"]])
+    monkeypatch.setattr(spans, "read", lambda run: spans.reduce(bare))
+    b = harness.breakdown(run)
+    assert b == trace.breakdown(run.trace)
+    assert all(k.startswith("bench.") or k == "none" for k, _ in b["idle_gaps"])
