@@ -8,14 +8,17 @@ One process, in order:
   2. tile     — two 4096x4096 tiles of a synthetic slide through
                 ``analyze_tile`` with the default ``WSIConfig``, once with
                 the Pallas kernels and once with the XLA references: mask
-                and labels must be equal, features within FEATURE_RTOL, and
-                each kernel's jitted wrapper must compile to a
-                ``tpu_custom_call``;
+                and labels must be equal, features within FEATURE_RTOL;
+                then again with the nucleus split on (``SPLIT``, as the
+                ``wsi_4k_ws`` configuration sets it), where no fixed-point
+                loop may stop at its cap; and each kernel's jitted wrapper
+                must compile to a ``tpu_custom_call``;
   3. runtime  — the same tiles through ``SegmentationStage`` /
                 ``FeatureStage`` on the Manager/Worker runtime
                 (``impl="auto"``) over tiered storage whose DMS tier is
-                socket-server subprocesses: the staged labels must equal
-                the tile phase's, with no failed stage;
+                socket-server subprocesses, without the split and with it:
+                the staged labels must equal the tile phase's, with no
+                failed stage;
   4. serve    — a ``RegionGateway`` with ``compute_impl="pallas"`` answers
                 ``deconv|threshold|ccl|count`` over a whole tile and
                 ``glcm`` over a 64x64 ROI: each must equal the chain run
@@ -28,6 +31,7 @@ success only, is ``{"ok": true, "device": {...}}`` as JAX reports it.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import shutil
 import sys
@@ -45,6 +49,7 @@ TILE = 4096  # WSIConfig().tile: the paper's 4K x 4K tiles
 # them (O(0.1)..O(100)) may differ by float rounding only
 FEATURE_RTOL, FEATURE_ATOL = 1e-5, 1e-6
 GLCM_ROI = 64  # WSIConfig().nucleus_roi
+SPLIT = {"split_h": 11, "split_min_area": 21}  # bench/configs/wsi_4k_ws.json
 
 
 def note(msg: str) -> None:
@@ -104,28 +109,43 @@ def tile_phase(tiles, cfg) -> list[dict]:
         np.testing.assert_allclose(f, wf, rtol=FEATURE_RTOL, atol=FEATURE_ATOL, err_msg=f"tile {i}")
         labels = np.asarray(got["labels"])
         n_obj = np.unique(labels[labels >= 0]).size
+        split = ""
+        if cfg.split_h is not None:
+            for impl, out in (("pallas", got), ("xla", want)):
+                check(not out["split"]["capped"], f"tile {i}: {impl} split capped {out['split']}")
+            split = f", split loop calls {got['split']['calls']}"
         note(
             f"tile {i}: {int(mask.sum())} nucleus pixels, {n_obj} objects, "
-            f"{f.shape[0]} ROIs; pallas {t1 - t0:.1f}s, xla {t2 - t1:.1f}s (with compile)"
+            f"{f.shape[0]} ROIs{split}; pallas {t1 - t0:.1f}s, xla {t2 - t1:.1f}s (with compile)"
         )
         results.append(got)
+    return results
 
-    # the Pallas kernels really ran: each wrapper compiles to a Mosaic call
-    x = jnp.asarray(tiles[0])
+
+def kernel_phase(tile, rois_shape, cfg) -> None:
+    """The Pallas kernels really ran: each wrapper compiles to a Mosaic call."""
+    import jax.numpy as jnp
+
+    from repro.kernels import ops, ref
+
+    x = jnp.asarray(tile)
     minv = jnp.asarray(ref.stain_inverse())
     plane = jnp.zeros((TILE, TILE), jnp.float32)
-    bins = jnp.zeros(results[0]["rois"].shape, jnp.int32)
+    plane_i = plane.astype(jnp.int32)
+    bins = jnp.zeros(rois_shape, jnp.int32)
     wrappers = {
         "color_deconv": (ops.color_deconv, (x, minv)),
         "morph_recon": (ops.morph_recon, (plane, plane)),
-        "connected_components": (ops.connected_components, (plane.astype(jnp.int32),)),
+        "connected_components": (ops.connected_components, (plane_i,)),
         "texture_features": (ops.texture_features, (bins, cfg.num_bins)),
+        "distance_transform": (ops.distance_transform, (plane_i,)),
+        "hmax_markers": (ops.hmax_markers, (plane_i, SPLIT["split_h"])),
+        "watershed": (ops.watershed, (plane_i, plane_i)),
     }
     for name, (fn, args) in wrappers.items():
         text = fn.lower(*args, impl="pallas").compile().as_text()
         check("tpu_custom_call" in text, f"{name}: no tpu_custom_call in the compiled wrapper")
     note(f"tpu_custom_call present in {', '.join(wrappers)}")
-    return results
 
 
 def runtime_phase(registry, rgb, cfg, want: list[dict]):
@@ -168,6 +188,10 @@ def runtime_phase(registry, rgb, cfg, want: list[dict]):
 
     failed = [e for e in env.manager.events if e[0] == "failed"]
     check(not failed, f"runtime: failed stage events {failed}")
+    if cfg.split_h is not None:
+        for part2, seg in segs:
+            check(seg.split is not None and not seg.split["capped"],
+                  f"runtime: split of {part2} {seg.split}")
     dms2 = registry.get("DMS2")
     for i, ((part2, seg), feat) in enumerate(zip(segs, feats)):
         # the stage stores BWLabel's labels in "Mask" (background -1)
@@ -181,7 +205,8 @@ def runtime_phase(registry, rgb, cfg, want: list[dict]):
             got_feats, want[i]["features"], rtol=FEATURE_RTOL, atol=FEATURE_ATOL,
             err_msg=f"runtime features, tile {i}",
         )
-    note(f"runtime: {len(segs)} tiles through Manager/WRM in {wall:.1f}s, no failed stage")
+    split = " with the split" if cfg.split_h is not None else ""
+    note(f"runtime: {len(segs)} tiles{split} through Manager/WRM in {wall:.1f}s, no failed stage")
     hema_key = segs[0][1].templates["Patient"].get("Hema").key
     return rgb_region.key, hema_key
 
@@ -238,8 +263,11 @@ def main() -> None:
     tiles = [rgb[:, :, i * TILE : (i + 1) * TILE] for i in range(2)]
     note(f"slide {rgb.shape} made in {time.perf_counter() - t0:.1f}s")
 
+    cfg_split = dataclasses.replace(cfg, **SPLIT)
     t0 = time.perf_counter()
     want = tile_phase(tiles, cfg)
+    want_split = tile_phase(tiles, cfg_split)
+    kernel_phase(tiles[0], want[0]["rois"].shape, cfg)
     note(f"tile phase passed in {time.perf_counter() - t0:.1f}s")
 
     root = tempfile.mkdtemp(prefix="chip_smoke_tiers_")
@@ -252,6 +280,7 @@ def main() -> None:
         note(f"DMS servers: {len(registry.server_group.procs)} subprocesses")
         t0 = time.perf_counter()
         rgb_key, hema_key = runtime_phase(registry, rgb, cfg, want)
+        runtime_phase(registry, rgb, cfg_split, want_split)
         note(f"runtime phase passed in {time.perf_counter() - t0:.1f}s")
         t0 = time.perf_counter()
         serve_phase(registry, rgb_key, hema_key)

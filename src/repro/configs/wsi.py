@@ -18,6 +18,11 @@ class WSIConfig:
     max_objects_per_tile: int = 512
     seg_threshold: float = 0.55
     partition: int = 1024  # worker partition edge (smoke/demo scale)
+    # the nucleus split (Pre-Watershed, Watershed, AreaThreshold), off when
+    # split_h is None: h-maxima height in squared pixels, and the fewest
+    # pixels an object keeps
+    split_h: int | None = None
+    split_min_area: int = 0
 
 
 # Per-operation GPU speedups following the paper's Fig. 16 profile — the

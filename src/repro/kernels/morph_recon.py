@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.fixed_point import fixed_point
 from repro.kernels.scan import roll_scan
 
 
@@ -98,7 +99,25 @@ def morph_recon_pallas(
     block_w: int = 256,
     interpret: bool = False,
 ) -> jax.Array:
-    """Fixed-point iteration of tile sweeps + cross-tile halo exchange.
+    """Fixed-point iteration of tile sweeps + cross-tile halo exchange."""
+    return morph_recon_pallas_counted(
+        marker, mask, max_iters=max_iters, n_sweeps=n_sweeps, block_h=block_h,
+        block_w=block_w, interpret=interpret,
+    )[0]
+
+
+def morph_recon_pallas_counted(
+    marker: jax.Array,
+    mask: jax.Array,
+    *,
+    max_iters: int = 64,
+    n_sweeps: int = 2,
+    block_h: int = 256,
+    block_w: int = 256,
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """``morph_recon_pallas``, with its kernel calls and whether it reached
+    its fixed point within ``max_iters`` (``fixed_point.fixed_point``).
 
     Between kernel calls, a 1-pixel neighborhood max is exchanged across
     the whole array (cheap XLA shifts) so wavefronts cross tile borders;
@@ -123,14 +142,4 @@ def morph_recon_pallas(
         neigh = jnp.maximum(jnp.maximum(up, dn), jnp.maximum(lf, rt))
         return jnp.minimum(mask_f, jnp.maximum(j, neigh))
 
-    def cond(state):
-        j, prev, it = state
-        return jnp.logical_and(jnp.any(j != prev), it < max_iters)
-
-    def body(state):
-        j, _, it = state
-        return sweep(halo(j), mask_f), j, it + 1
-
-    j1 = sweep(j0, mask_f)
-    j, _, _ = jax.lax.while_loop(cond, body, (j1, j0, jnp.asarray(1)))
-    return j
+    return fixed_point(lambda j: sweep(halo(j), mask_f), j0, sweep(j0, mask_f), max_iters)

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.distance import FAR, no_background
+from repro.kernels.fixed_point import fixed_point
 from repro.kernels.scan import roll_scan
 
 # --------------------------------------------------------------------------
@@ -95,19 +97,16 @@ def morph_recon_sweep_ref(marker: jax.Array, mask: jax.Array) -> jax.Array:
 
 def morph_recon_ref(marker: jax.Array, mask: jax.Array, max_iters: int = 256) -> jax.Array:
     """Grayscale reconstruction by dilation to fixed point (4-connectivity)."""
+    return morph_recon_ref_counted(marker, mask, max_iters)[0]
 
-    def cond(state):
-        j, prev, it = state
-        return jnp.logical_and(jnp.any(j != prev), it < max_iters)
 
-    def body(state):
-        j, _, it = state
-        return morph_recon_sweep_ref(j, mask), j, it + 1
-
+def morph_recon_ref_counted(
+    marker: jax.Array, mask: jax.Array, max_iters: int = 256
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """``morph_recon_ref``, with its sweeps and whether it converged."""
     j0 = jnp.minimum(marker, mask)
-    j1 = morph_recon_sweep_ref(j0, mask)
-    j, _, _ = jax.lax.while_loop(cond, body, (j1, j0, jnp.asarray(1)))
-    return j
+    step = lambda j: morph_recon_sweep_ref(j, mask)  # noqa: E731
+    return fixed_point(step, j0, step(j0), max_iters)
 
 
 def fill_holes_seed(mask01: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -202,24 +201,191 @@ def ccl_sweep_ref(labels: jax.Array, mask: jax.Array) -> jax.Array:
 
 def ccl_ref(mask: jax.Array, max_iters: int = 256) -> jax.Array:
     """Min-label propagation to fixed point; canonical (min flat index)."""
+    return ccl_ref_counted(mask, max_iters)[0]
+
+
+def ccl_ref_counted(
+    mask: jax.Array, max_iters: int = 256
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """``ccl_ref``, with its sweeps and whether it converged."""
     mask_b = mask != 0
     h, w = mask.shape[-2], mask.shape[-1]
     init = jnp.arange(h * w, dtype=jnp.int32).reshape(mask.shape[-2:])
     init = jnp.broadcast_to(init, mask.shape)
     big = jnp.asarray(jnp.iinfo(jnp.int32).max, jnp.int32)
     labels = jnp.where(mask_b, init, big)
+    step = lambda l: ccl_sweep_ref(l, mask_b)  # noqa: E731
+    l, calls, converged = fixed_point(step, labels, step(labels), max_iters)
+    return jnp.where(mask_b, l, -1), calls, converged
+
+
+# --------------------------------------------------------------------------
+# Nucleus split: distance transform, h-maxima markers, watershed
+# --------------------------------------------------------------------------
+# Every step is 4-connected and keeps the opened mask as its domain: the
+# graph has the mask's pixels as nodes and an edge between 4-neighbours
+# that are both in it, and R(marker, mask) is reconstruction by dilation on
+# that graph (``morph_recon`` with background 0 below every value on the
+# mask, so nothing crosses the background).
+#
+#   1. d2(p): squared Euclidean distance to the nearest background pixel of
+#      the tile, int32, 0 on background; pixels outside the tile are not
+#      background (scipy.ndimage.distance_transform_edt's convention).
+#   2. Markers (Pre-Watershed): g = R(d2 - h, d2); marker pixels are where
+#      g - R(g - 1, g) >= 1, the regional maxima of the h-maxima transform;
+#      each marker is labelled by its smallest raster index (a CCL).  The
+#      values are small integers, exact in float32 (d2 < 2**24).
+#   3. Cost: c = R(m, d2), m = d2 on marker pixels and 0 elsewhere: the
+#      maximin bottleneck of a path from any marker.
+#   4. Watershed: an edge q -> p between 4-neighbours on the mask when
+#      c(p) == min(c(q), d2(p)); a pixel's label is the smallest marker label
+#      that reaches it along such edges.  This is the least fixed point of a
+#      min over a fixed graph, so every algorithm gives the same labels.
+#      Each basin is then relabelled by its smallest raster index.
+#   5. AreaThreshold: objects of fewer than min_area pixels become
+#      background (-1, and 0 in the mask).
+#
+# Every component of the mask holds a marker: g attains its maximum over the
+# component on a plateau, which is a regional maximum.  A component where
+# d2 <= h throughout has g constant, so it is one marker and stays one
+# object, as without the split.
+#
+# Every pixel of a component is reached.  By the fixed point of step 3, a
+# pixel p off the markers has c(p) = min(d2(p), max_q c(q)) over its
+# neighbours q, and c(p) >= 1 on the mask.  Suppose some pixels were not
+# reached, and let p be one with the largest c among them; take a path of
+# bottleneck c(p) from a marker x0 to p, and its first unreached pixel x_i.
+# Every pixel of the path has c and d2 >= c(p), and x_i has c(x_i) = c(p)
+# (no unreached c is larger), so c(x_i) = min(c(x_{i-1}), d2(x_i)): the
+# edge x_{i-1} -> x_i exists and x_{i-1} is reached, so x_i is too.
+#
+# Every basin is connected and holds a pixel of its marker: if p has label l
+# along a path from marker l, every pixel on that path has label l, for a
+# smaller label reaching one of them would reach p as well.
+def distance_transform_ref(mask: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Step 1: (H, W) mask -> (int32 squared distances, window steps).
+
+    Along rows, the distance to the nearest background column from running
+    max/min of background indices; along columns, ``min_i' a(i', j)**2 +
+    (i - i')**2`` over a window widened one offset a step until each pixel's
+    minimum is at most (window + 1)**2, beyond which no candidate can win.
+    """
+    h, w = mask.shape
+    fg = mask != 0
+    col = jnp.broadcast_to(jnp.arange(w, dtype=jnp.int32), (h, w))
+    last = jax.lax.cummax(jnp.where(fg, -FAR, col), axis=1)
+    nxt = jax.lax.cummin(jnp.where(fg, w + FAR, col), axis=1, reverse=True)
+    a = jnp.minimum(jnp.minimum(col - last, nxt - col), FAR)
+    # rows moved by a dynamic offset t: slices of the column range padded once
+    pad = jnp.pad(a, ((h, h), (0, 0)), constant_values=FAR)
 
     def cond(state):
-        l, prev, it = state
-        return jnp.logical_and(jnp.any(l != prev), it < max_iters)
+        best, t = state
+        return jnp.logical_and(t < h - 1, jnp.any(best > (t + 1) * (t + 1)))
 
     def body(state):
-        l, _, it = state
-        return ccl_sweep_ref(l, mask_b), l, it + 1
+        best, t = state
+        t = t + 1
+        up = jax.lax.dynamic_slice_in_dim(pad, h - t, h, axis=0)
+        down = jax.lax.dynamic_slice_in_dim(pad, h + t, h, axis=0)
+        return jnp.minimum(best, jnp.minimum(up * up, down * down) + t * t), t
 
-    l1 = ccl_sweep_ref(labels, mask_b)
-    l, _, _ = jax.lax.while_loop(cond, body, (l1, labels, jnp.asarray(1)))
-    return jnp.where(mask_b, l, -1)
+    has_bg = jnp.any(~fg)
+    best, t = jax.lax.while_loop(cond, body, (a * a, jnp.where(has_bg, 0, h)))
+    d2 = jnp.where(has_bg, best, no_background(h, w))
+    return d2, jnp.where(has_bg, t, 0)
+
+
+def _neighbour(x: jax.Array, k: int, fill) -> jax.Array:
+    """Each pixel's neighbour in edge bit k's direction: above, below, left,
+    right; ``fill`` off the image."""
+    pads = (((1, 0), (0, 0)), ((0, 1), (0, 0)), ((0, 0), (1, 0)), ((0, 0), (0, 1)))
+    cut = (x[:-1, :], x[1:, :], x[:, :-1], x[:, 1:])[k]
+    return jnp.pad(cut, pads[k], constant_values=fill)
+
+
+def _edge_bits(ok) -> jax.Array:
+    """int32 edge bits from ``ok(k)``, the bool plane of bit k."""
+    edges = jnp.zeros((), jnp.int32)
+    for k in range(4):
+        edges = edges | (ok(k).astype(jnp.int32) << k)
+    return edges
+
+
+def watershed_edges(d2: jax.Array, cost: jax.Array) -> jax.Array:
+    """Step 4's edges as int32 bits, one per neighbour a label may come from:
+    bit 0 from above, 1 from below, 2 from the left, 3 from the right.  An
+    edge q -> p needs both on the mask (d2 > 0) and c(p) == min(c(q), d2(p))."""
+    on = d2 > 0
+    d2 = d2.astype(cost.dtype)
+    return _edge_bits(lambda k: on & _neighbour(on, k, False)
+                      & (cost == jnp.minimum(_neighbour(cost, k, 0), d2)))
+
+
+def same_label_edges(labels: jax.Array, on: jax.Array) -> jax.Array:
+    """Edge bits between 4-neighbours that are both ``on`` and carry the same
+    label: propagating raster indices along them names each connected basin
+    by its smallest raster index (step 4's relabelling)."""
+    return _edge_bits(lambda k: on & _neighbour(on, k, False)
+                      & (labels == _neighbour(labels, k, -1)))
+
+
+def watershed_ref(
+    labels: jax.Array, edges: jax.Array, max_iters: int = 256
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Step 4's propagation: ``labels`` on the markers (int32 max elsewhere)
+    carried along ``edges`` by directional min scans, one 4-direction sweep a
+    step, to the least fixed point.  -> (labels, sweeps, converged)."""
+    flags = [((edges >> k) & 1) != 0 for k in range(4)]
+
+    def step(l):
+        for (axis, reverse), flag in zip(((-2, False), (-2, True), (-1, False), (-1, True)), flags):
+            l = _ccl_scan_1d(l, flag, axis=axis, reverse=reverse)
+        return l
+
+    return fixed_point(step, labels, step(labels), max_iters)
+
+
+def split_nuclei_host(mask: np.ndarray, h: int, min_area: int) -> np.ndarray:
+    """Steps 1-5 in NumPy and SciPy (host oracle): opened mask -> int32
+    labels, -1 off.  Reconstructions by geodesic dilation one pixel a step,
+    the watershed by relaxation over its edges, both to the fixed point."""
+    from scipy import ndimage
+
+    on = np.asarray(mask) != 0
+    d2 = np.rint(ndimage.distance_transform_edt(on) ** 2).astype(np.int64)
+    low = np.iinfo(np.int64).min // 2  # off the mask: never passes a value
+
+    def neighbours(x, fill):
+        p = np.pad(np.where(on, x, fill), 1, constant_values=fill)
+        return p[:-2, 1:-1], p[2:, 1:-1], p[1:-1, :-2], p[1:-1, 2:]
+
+    def recon(marker, ceiling):
+        x = np.where(on, np.minimum(marker, ceiling), low)
+        while True:
+            nxt = np.where(on, np.minimum(ceiling, np.maximum.reduce([x, *neighbours(x, low)])), low)
+            if np.array_equal(nxt, x):
+                return x
+            x = nxt
+
+    g = recon(d2 - h, d2)
+    markers = on & (g - recon(g - 1, g) >= 1)
+    big = np.iinfo(np.int64).max
+    labels = np.where(markers, ccl_unionfind_host(markers).astype(np.int64), big)
+    cost = recon(np.where(markers, d2, 0), d2)
+    edge = [on & (cost == np.minimum(c, d2)) for c in neighbours(cost, low)]
+    while True:
+        came = [np.where(e, v, big) for e, v in zip(edge, neighbours(labels, big))]
+        nxt = np.where(on, np.minimum.reduce([labels, *came]), big)
+        if np.array_equal(nxt, labels):
+            break
+        labels = nxt
+    out = np.full(on.shape, -1, np.int32)
+    ids, inv, area = np.unique(labels[on], return_inverse=True, return_counts=True)
+    first = np.full(len(ids), big)
+    np.minimum.at(first, inv, np.flatnonzero(on))
+    out[on] = np.where(area[inv] >= min_area, first[inv], -1)
+    return out
 
 
 # --------------------------------------------------------------------------

@@ -8,6 +8,7 @@ from repro.pipeline.wsi import (
     extract_object_rois,
     make_wsi_storage,
     segment_tile,
+    split_nuclei,
 )
 
 __all__ = [
@@ -20,4 +21,5 @@ __all__ = [
     "extract_object_rois",
     "make_wsi_storage",
     "segment_tile",
+    "split_nuclei",
 ]

@@ -16,6 +16,7 @@ pipeline runs the Pallas kernels on TPU and the jnp references on CPU.
 """
 from __future__ import annotations
 
+import functools
 import os
 import tempfile
 from typing import Any
@@ -50,8 +51,76 @@ def segment_tile(rgb: jax.Array, cfg: WSIConfig, impl: str = "auto") -> dict:
                          * jnp.roll(filled, 1, -2) * jnp.roll(filled, -1, -2))
     opened = ops.morph_recon(marker, filled, impl=impl)
     mask = (opened > 0.5).astype(jnp.int32)
+    if cfg.split_h is not None:
+        return {**split_nuclei(mask, cfg, impl), "hematoxylin": hema_n}
     labels = ops.connected_components(mask, impl=impl)
     return {"mask": mask, "labels": labels, "hematoxylin": hema_n}
+
+
+# ---------------------------------------------------------------------------
+# Nucleus split: the equations are in kernels/ref.py ("Nucleus split")
+# ---------------------------------------------------------------------------
+def pre_watershed(
+    mask: jax.Array, cfg: WSIConfig, impl: str = "auto", max_iters: int = 128
+) -> tuple[jax.Array, jax.Array, dict]:
+    """Steps 1-2: the opened mask -> (squared distances, marker labels, the
+    loops' (kernel calls, converged))."""
+    d2, edt_calls = ops.distance_transform(mask, impl=impl)
+    markers, calls, ok = ops.hmax_markers(d2, cfg.split_h, impl=impl, max_iters=max_iters)
+    loops = {"edt": (edt_calls, True)}
+    loops.update({k: (calls[i], ok[i]) for i, k in enumerate(("hmax", "maxima", "markers"))})
+    return d2, markers, loops
+
+
+def watershed_basins(
+    d2: jax.Array, markers: jax.Array, impl: str = "auto", max_iters: int = 128
+) -> tuple[jax.Array, dict]:
+    """Steps 3-4: -> (basin labels, the loops' (kernel calls, converged))."""
+    basins, calls, ok = ops.watershed(d2, markers, impl=impl, max_iters=max_iters)
+    names = ("cost", "watershed", "relabel")
+    return basins, {k: (calls[i], ok[i]) for i, k in enumerate(names)}
+
+
+@functools.partial(jax.jit, static_argnames=("min_area",))
+def area_threshold(basins: jax.Array, min_area: int) -> jax.Array:
+    """Step 5: basins of fewer than ``min_area`` pixels dropped (-1), and
+    pixels no marker reached (a loop stopped at its cap) with them.
+
+    By two sorts and running max/min, with no scatter or gather, which a
+    TPU runs an element at a time: sorted by label, each basin is one run,
+    whose length is its area; sorting back by raster index returns each
+    pixel's verdict to its place."""
+    n = basins.size
+    flat = basins.ravel()
+    pos = jnp.arange(n, dtype=jnp.int32)
+    key, idx = jax.lax.sort((jnp.where((flat >= 0) & (flat < n), flat, n), pos), num_keys=1)
+    change = key[1:] != key[:-1]
+    start = jax.lax.cummax(jnp.where(jnp.concatenate([jnp.ones(1, bool), change]), pos, 0))
+    end = jax.lax.cummin(jnp.where(jnp.concatenate([change, jnp.ones(1, bool)]), pos, n - 1),
+                         reverse=True)
+    keep = (key < n) & (end - start + 1 >= min_area)
+    _, out = jax.lax.sort((idx, jnp.where(keep, key, -1)), num_keys=1)
+    return out.reshape(basins.shape)
+
+
+def split_report(loops: dict) -> dict:
+    """The loops' kernel calls and the names of those stopped at their cap,
+    read from the device at once."""
+    got = jax.device_get(loops)
+    return {"calls": {k: int(c) for k, (c, _) in got.items()},
+            "capped": [k for k, (_, ok) in got.items() if not ok]}
+
+
+def split_nuclei(mask: jax.Array, cfg: WSIConfig, impl: str = "auto",
+                 max_iters: int = 128) -> dict:
+    """Steps 1-5 on the opened mask -> {"mask", "labels", "split"}, where
+    "split" is ``split_report``'s: a loop in "capped" stopped short of its
+    fixed point, and the labels may then differ from the reference's."""
+    d2, markers, loops = pre_watershed(mask, cfg, impl, max_iters)
+    basins, more = watershed_basins(d2, markers, impl, max_iters)
+    labels = area_threshold(basins, cfg.split_min_area)
+    return {"mask": (labels >= 0).astype(jnp.int32), "labels": labels,
+            "split": split_report({**loops, **more})}
 
 
 def extract_object_rois(
@@ -314,12 +383,17 @@ def _task_cost(op: str, scale: float = 1.0, input_bytes: int = 0) -> TaskCost:
 
 
 class SegmentationStage(Stage):
-    """Reads "RGB", produces "Mask" (+ float labels channel)."""
+    """Reads "RGB", produces "Mask" (labels, -1 off) and "Hema".
+
+    With ``cfg.split_h`` set, BWLabel gives way to the nucleus split's
+    tasks, Pre-Watershed, Watershed and AreaThreshold, and ``split`` holds
+    ``split_report``'s after the stage has run."""
 
     def __init__(self, cfg: WSIConfig | None = None, impl: str = "auto") -> None:
         super().__init__("Segmentation")
         self.cfg = cfg or WSIConfig()
         self.impl = impl
+        self.split: dict | None = None
 
     def run(self, ctx) -> Any:
         rgb_region = ctx.region("Patient", "RGB")
@@ -341,9 +415,9 @@ class SegmentationStage(Stage):
 
         results: dict[str, Any] = {}
 
-        def op(name, fn, deps=(), region_key=None, input_bytes=0):
+        def op(name, fn, deps=(), region_key=None, input_bytes=0, key=None):
             def work():
-                results[name] = fn()
+                results[key or name] = fn()
 
             return ctx.submit(
                 Task(
@@ -388,17 +462,32 @@ class SegmentationStage(Stage):
             return ops.morph_recon(marker, filled, impl=self.impl)
 
         t_recon = op("ReconToNuclei", recon, deps=[t_fill])
-        t_label = op(
-            "BWLabel",
-            lambda: ops.connected_components(
-                (results["ReconToNuclei"] > 0.5).astype(jnp.int32), impl=self.impl
-            ),
-            deps=[t_recon],
-        )
+
+        def opened():
+            return (results["ReconToNuclei"] > 0.5).astype(jnp.int32)
+
+        cfg = self.cfg
+        if cfg.split_h is None:
+            t_label = op(
+                "BWLabel", lambda: ops.connected_components(opened(), impl=self.impl),
+                deps=[t_recon], key="labels",
+            )
+        else:
+            t_pre = op("Pre-Watershed", lambda: pre_watershed(opened(), cfg, self.impl),
+                       deps=[t_recon])
+            t_ws = op("Watershed",
+                      lambda: watershed_basins(*results["Pre-Watershed"][:2], self.impl),
+                      deps=[t_pre])
+            t_label = op("AreaThreshold",
+                         lambda: area_threshold(results["Watershed"][0], cfg.split_min_area),
+                         deps=[t_ws], key="labels")
 
         def finalize():
-            mask_region.set_data(np.asarray(results["BWLabel"], np.int32))
+            mask_region.set_data(np.asarray(results["labels"], np.int32))
             hema_region.set_data(np.asarray(results["hema_n"], np.float32))
+            if cfg.split_h is not None:
+                self.split = split_report({**results["Pre-Watershed"][2],
+                                           **results["Watershed"][1]})
 
         ctx.submit(Task("stage-finalize", cpu_fn=finalize, deps=[t_label],
                         cost=TaskCost(cpu_s=0.05)))

@@ -1,4 +1,5 @@
-"""The WSI Pallas kernels compile for a TPU v5e at the paper's full size.
+"""The WSI Pallas kernels, the nucleus split's among them, compile for a
+TPU v5e at the paper's full size.
 
 Interpret mode (tests/test_kernels.py) runs the kernel bodies on the CPU
 but never asks Mosaic to lower them; these tests compile each kernel with
@@ -18,8 +19,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.ccl import ccl_pallas
+from repro.kernels.ccl import ccl_pallas, watershed_pallas
 from repro.kernels.color_deconv import color_deconv_pallas
+from repro.kernels.distance import distance_transform_pallas
 from repro.kernels.glcm import glcm_pallas
 from repro.kernels.morph_recon import morph_recon_pallas
 
@@ -62,6 +64,10 @@ def _spec(shape, dtype, sharding):
             (morph_recon_pallas, [((TILE, TILE), jnp.float32)] * 2), id="morph_recon"
         ),
         pytest.param((ccl_pallas, [((TILE, TILE), jnp.int32)]), id="ccl"),
+        pytest.param(
+            (distance_transform_pallas, [((TILE, TILE), jnp.int32)]), id="distance_transform"
+        ),
+        pytest.param((watershed_pallas, [((TILE, TILE), jnp.int32)] * 2), id="watershed"),
         pytest.param(
             (lambda b: glcm_pallas(b, BINS), [((ROIS, ROI, ROI), jnp.int32)]), id="glcm"
         ),
